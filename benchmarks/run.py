@@ -40,6 +40,8 @@ import re
 import sys
 import traceback
 
+from repro.launch.compile_cache import configure_compile_cache
+
 from . import (bench_apps, bench_area, bench_data_movement,
                bench_dualitycache, bench_energy, bench_reliability,
                bench_roofline, bench_serving, bench_table5_counts,
@@ -198,6 +200,7 @@ def main() -> None:
                     help="multiplicative guard for noisy warm-path wall "
                          "keys under --compare (default 4.0)")
     args = ap.parse_args()
+    configure_compile_cache()
     names = (args.only.split(",") if args.only
              else list(SMOKE) if args.smoke else list(BENCHES))
     capture = (args.smoke or args.artifact is not None

@@ -44,7 +44,7 @@ def xnor_layer(x, w):
     """Binary fully-connected layer: sign inputs × sign weights via the
     packed XNOR-popcount Pallas kernel (VGG/LeNet building block)."""
     xp, wp = pack_signs(jnp.asarray(x)), pack_signs(jnp.asarray(w))
-    return np.asarray(bitserial_matmul(xp, wp, x.shape[1], interpret=True))
+    return np.asarray(bitserial_matmul(xp, wp, x.shape[1]))
 
 
 # --- a kernel on a user-defined operation ------------------------------------

@@ -16,8 +16,8 @@ Registered backends:
   vanish, constants fold; the TPU-native fast path.  jit/vmap-compatible.
 * ``pallas``    — the Fig.-7 control-unit FSM as a Pallas kernel
   (:func:`repro.kernels.ops.run_trace_kernel`): the trace's int32 command
-  stream driving a VMEM row file.  ``interpret=True`` runs it on CPU; on a
-  real TPU the same kernel is the explicitly-tiled memory-traffic path.
+  stream driving a VMEM row file, compiled on a TPU and interpreted on the
+  CPU (:func:`repro.kernels.interpret_mode` decides).
 
 New substrates (real-DRAM timing models, GPU bit-slice engines, …) plug in
 with :func:`register_backend` — a ``BackendFn`` takes ``(trace, operands,
@@ -885,9 +885,7 @@ def _unrolled_execute(trace: LoweredTrace, operands: dict,
 def _pallas_execute(trace: LoweredTrace, operands: dict,
                     out_bits=None) -> dict:
     from ..kernels.ops import run_trace_kernel
-    interpret = jax.default_backend() != "tpu"
-    return run_trace_kernel(trace, operands, out_bits=out_bits,
-                            interpret=interpret)
+    return run_trace_kernel(trace, operands, out_bits=out_bits)
 
 
 def _reference_execute(trace: LoweredTrace, operands: dict,

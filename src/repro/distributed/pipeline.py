@@ -15,7 +15,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as PS
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(stage_fn, stage_params, x, *, mesh: Mesh,
@@ -69,8 +68,8 @@ def pipeline_apply(stage_fn, stage_params, x, *, mesh: Mesh,
 
     in_specs = (jax.tree.map(lambda _: PS(axis), stage_params), PS())
     out_specs = PS(axis)
-    fn = shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     stacked = fn(stage_params, x)          # (P·M, mb, ...)
     return stacked[(p - 1) * m:]
 

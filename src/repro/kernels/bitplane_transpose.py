@@ -7,8 +7,10 @@ groups of horizontally-laid-out words into 32 bit-planes in 5 vector steps.
 
 Layout choice (TPU-native): the 32-element axis lives on *sublanes* and the
 group axis on *lanes*, so every masked shift is a sublane roll + vector
-bitwise op — no lane shuffles, no gathers.  Block shape (32, 128) matches the
-8×128 vreg tiling (4 vregs per block).
+bitwise op — no lane shuffles, no gathers.  The network is the LSB-first
+one: element e's bit i lands in plane i, lane bit e, with no reversal
+pass.  Block shape (32, 128) matches the 8×128 vreg tiling (4 vregs per
+block).
 """
 from __future__ import annotations
 
@@ -18,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import interpret_mode
+
 GROUP = 32          # elements per transpose group (= bits per word)
 LANE_BLOCK = 128    # groups per kernel block (TPU lane width)
 
@@ -26,18 +30,20 @@ def _transpose32_block(a: jax.Array) -> jax.Array:
     """Bit-transpose a (32, G) uint32 block along the sublane axis.
 
     a[e, g] = word of element e in group g; returns p[i, g] whose lane bit e
-    is bit i of a[e, g].  Masked-shift network, 5 stages.
+    is bit i of a[e, g].  Masked-shift network, 5 stages: stage j swaps the
+    high j-bit half of every 2j-bit field of row e (e & j == 0) with the low
+    half of the same field of row e + j.
     """
     e_idx = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
     j = 16
-    m = jnp.uint32(0x0000FFFF)
+    m = jnp.uint32(0x0000FFFF)       # low half of every 2j-bit field
     while j:
-        upper_sel = (e_idx & j) == 0
-        partner_dn = pl.roll(a, -j, 0) if hasattr(pl, "roll") else jnp.roll(a, -j, 0)
-        partner_up = pl.roll(a, j, 0) if hasattr(pl, "roll") else jnp.roll(a, j, 0)
-        t_up = (a ^ (partner_dn >> j)) & m           # valid on upper lanes
-        t_dn = ((partner_up ^ (a >> j)) & m) << j    # t computed at partner
-        a = jnp.where(upper_sel, a ^ t_up, a ^ t_dn)
+        upper = (e_idx & j) == 0
+        partner_dn = jnp.roll(a, -j, 0)          # row e + j
+        partner_up = jnp.roll(a, j, 0)           # row e - j
+        t_up = ((a >> j) ^ partner_dn) & m       # t as row e computes it
+        t_dn = ((partner_up >> j) ^ a) & m       # t as row e + j computes it
+        a = jnp.where(upper, a ^ (t_up << j), a ^ t_dn)
         j >>= 1
         m = m ^ (m << j) if j else m
     return a
@@ -45,20 +51,18 @@ def _transpose32_block(a: jax.Array) -> jax.Array:
 
 def _fwd_kernel(x_ref, o_ref):
     # x_ref: (32, LANE_BLOCK) element-words (sublane e, lane g).
-    # The HD network computes the mirrored transpose (out[i] bit e =
-    # in[31−e] bit 31−i); reversing the sublane axis on both sides yields
-    # the LSB-first transpose (out[i] bit e = in[e] bit i).
-    x = jax.lax.rev(x_ref[...], (0,))
-    o_ref[...] = jax.lax.rev(_transpose32_block(x), (0,))
+    o_ref[...] = _transpose32_block(x_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def bitplane_transpose(groups: jax.Array, interpret: bool = False) -> jax.Array:
+def bitplane_transpose(groups: jax.Array,
+                       interpret: bool | None = None) -> jax.Array:
     """uint32[G, 32] horizontal element words → uint32[32, G] bit-planes.
 
     G must be a multiple of 128.  out[i, g] lane-bit e = bit i of
     groups[g, e] — but note the kernel works in (32, G) orientation, so we
-    feed groups.T and the result is directly (32, G).
+    feed groups.T and the result is directly (32, G).  ``interpret=None``
+    follows :func:`repro.kernels.interpret_mode`.
     """
     g, e = groups.shape
     assert e == GROUP and g % LANE_BLOCK == 0, (g, e)
@@ -69,6 +73,6 @@ def bitplane_transpose(groups: jax.Array, interpret: bool = False) -> jax.Array:
         in_specs=[pl.BlockSpec((GROUP, LANE_BLOCK), lambda i: (0, i))],
         out_specs=pl.BlockSpec((GROUP, LANE_BLOCK), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((GROUP, g), jnp.uint32),
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(x)
     return out

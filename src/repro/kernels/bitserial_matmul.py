@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import interpret_mode
+
 
 def _popcount(v: jax.Array) -> jax.Array:
     v = v - ((v >> 1) & jnp.uint32(0x55555555))
@@ -47,9 +49,14 @@ def _kernel(a_ref, b_ref, o_ref, *, bk: int):
 @functools.partial(jax.jit,
                    static_argnames=("k_bits", "bm", "bn", "bk", "interpret"))
 def bitserial_matmul(a_packed: jax.Array, b_packed: jax.Array, k_bits: int,
-                     bm: int = 128, bn: int = 128, bk: int = 8,
-                     interpret: bool = False) -> jax.Array:
-    """a: uint32[M, K/32] sign-packed; b: uint32[N, K/32]; → int32[M, N]."""
+                     bm: int = 128, bn: int = 128, bk: int = 128,
+                     interpret: bool | None = None) -> jax.Array:
+    """a: uint32[M, K/32] sign-packed; b: uint32[N, K/32]; → int32[M, N].
+
+    Blocks are clipped to the operand; a K block narrower than the packed
+    width must stay a multiple of 128 words on the TPU (lane tiling).
+    ``interpret=None`` follows :func:`repro.kernels.interpret_mode`.
+    """
     m, kw = a_packed.shape
     n, kw2 = b_packed.shape
     assert kw == kw2 and k_bits == kw * 32
@@ -62,7 +69,7 @@ def bitserial_matmul(a_packed: jax.Array, b_packed: jax.Array, k_bits: int,
                   pl.BlockSpec((bn, bk), lambda i, j, k: (j, k))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(a_packed, b_packed)
     return k_bits - 2 * mismatches
 

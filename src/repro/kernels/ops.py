@@ -26,10 +26,11 @@ __all__ = ["bitplane_transpose", "bitserial_matmul", "pack_signs",
 
 def run_trace_kernel(trace: LoweredTrace, operands: dict[str, jax.Array],
                      out_bits: dict[str, int] | None = None,
-                     interpret: bool = True) -> dict[str, jax.Array]:
+                     interpret: bool | None = None) -> dict[str, jax.Array]:
     """Execute a lowered command trace via the Pallas row-file kernel.
 
-    operands: name → uint32[n_bits, W] bit-planes.
+    operands: name → uint32[n_bits, W] bit-planes.  ``interpret=None``
+    follows :func:`repro.kernels.interpret_mode`.
     """
     words = next(iter(operands.values())).shape[1]
     zero = jnp.zeros((words,), jnp.uint32)
@@ -58,14 +59,14 @@ def run_trace_kernel(trace: LoweredTrace, operands: dict[str, jax.Array],
 
 def run_uprogram_kernel(prog: UProgram, operands: dict[str, jax.Array],
                         out_bits: dict[str, int] | None = None,
-                        interpret: bool = True) -> dict[str, jax.Array]:
+                        interpret: bool | None = None) -> dict[str, jax.Array]:
     """μProgram-level entry: lower (memoized), then run the trace kernel."""
     return run_trace_kernel(lower_program(prog), operands,
                             out_bits=out_bits, interpret=interpret)
 
 
 def transpose_to_planes(x: jax.Array, n_bits: int,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool | None = None) -> jax.Array:
     """int32[E] → uint32[n_bits, E/32] via the Pallas transpose kernel.
 
     E must be a multiple of 32·128 (one kernel block); callers pad.

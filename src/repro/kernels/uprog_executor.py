@@ -10,11 +10,12 @@ Command encoding (int32[N, 4]):
     op = 0: COPY  row|a| ← read(b)                      (AAP)
     op = 1: MAJ   rows |a|,|b|,|c| ← MAJ(read(a),read(b),read(c))   (AP)
 Row operands are 1-based; a negative index reads/writes the complement
-(dual-contact-cell port).  Index 0 is reserved (reads as constant 0; the
-C1 row is a regular row pre-filled with ones).
+(dual-contact-cell port).  Index 0 never occurs: the constant rows C0/C1
+are regular rows (C1 pre-filled with ones).
 
 The command stream lives in SMEM via PrefetchScalarGridSpec so the FSM loop
-is scalar-driven while row data stays vectorized.
+is scalar-driven while row data stays vectorized.  SMEM holds 1 MiB, so a
+single trace is limited to about 65,000 commands.
 """
 from __future__ import annotations
 
@@ -25,49 +26,51 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret_mode
+
 LANE_BLOCK = 128
-SUBLANE = 8
-
-
-def _read(rows, idx):
-    v = rows[jnp.abs(idx) - 1]
-    return jnp.where(idx < 0, ~v, v)
-
-
-def _write(rows, idx, val):
-    val = jnp.where(idx < 0, ~val, val)
-    return rows.at[jnp.abs(idx) - 1].set(val)
 
 
 def _kernel(cmds_ref, rows_ref, out_ref, *, n_cmds: int):
-    rows = rows_ref[...]
+    # the row file lives in out_ref; every command reads and writes single
+    # (1, LANE_BLOCK) rows at scalar offsets from the SMEM command stream
+    out_ref[...] = rows_ref[...]
 
-    def body(t, rows):
-        op = cmds_ref[t, 0]
-        a, b, c = cmds_ref[t, 1], cmds_ref[t, 2], cmds_ref[t, 3]
-        va, vb, vc = _read(rows, a), _read(rows, b), _read(rows, c)
+    def read(idx):
+        v = out_ref[pl.ds(jnp.abs(idx) - 1, 1), :]
+        return jnp.where(idx < 0, ~v, v)
+
+    def write(idx, val):
+        out_ref[pl.ds(jnp.abs(idx) - 1, 1), :] = jnp.where(idx < 0, ~val, val)
+
+    def body(t, carry):
+        base = 4 * t
+        op, a = cmds_ref[base], cmds_ref[base + 1]
+        b, c = cmds_ref[base + 2], cmds_ref[base + 3]
+        va, vb, vc = read(a), read(b), read(c)
         maj = (va & vb) | (va & vc) | (vb & vc)
         is_maj = op == 1
-        val_a = jnp.where(is_maj, maj, vb)
-        rows = _write(rows, a, val_a)
+        write(a, jnp.where(is_maj, maj, vb))
 
-        def maj_writes(rows):
-            return _write(_write(rows, b, maj), c, maj)
+        @pl.when(is_maj)
+        def _maj_writes():
+            write(b, maj)
+            write(c, maj)
 
-        rows = jax.lax.cond(is_maj, maj_writes, lambda r: r, rows)
-        return rows
+        return carry
 
-    rows = jax.lax.fori_loop(0, n_cmds, body, rows)
-    out_ref[...] = rows
+    jax.lax.fori_loop(0, n_cmds, body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def uprog_execute(cmds: jax.Array, rows: jax.Array,
-                  interpret: bool = False) -> jax.Array:
+                  interpret: bool | None = None) -> jax.Array:
     """Run an encoded command stream over a row file.
 
     cmds: int32[N, 4]; rows: uint32[R, W] with W a multiple of 128.
-    Returns the final row file.
+    Returns the final row file.  ``interpret=None`` follows
+    :func:`repro.kernels.interpret_mode`.  The commands go to SMEM flat: a
+    2-D int32[N, 4] operand would be padded to N × 128 words there.
     """
     n_cmds = cmds.shape[0]
     r, w = rows.shape
@@ -82,8 +85,8 @@ def uprog_execute(cmds: jax.Array, rows: jax.Array,
             out_specs=pl.BlockSpec((r, LANE_BLOCK), lambda i, cmds: (0, i)),
         ),
         out_shape=jax.ShapeDtypeStruct((r, w), jnp.uint32),
-        interpret=interpret,
-    )(cmds, rows)
+        interpret=interpret_mode() if interpret is None else interpret,
+    )(cmds.reshape(-1), rows)
 
 
 # ---------------------------------------------------------------------------

@@ -26,7 +26,6 @@ from jax.sharding import PartitionSpec as PS
 
 from ..configs import ARCHS, get_config
 from ..configs.base import SHAPES, ModelConfig, ShapeConfig, shape_applicable
-from ..distributed.compat import cost_analysis_dict
 from ..distributed.sharding import (batch_shardings, cache_shardings,
                                     replicated, tree_shardings)
 from ..models.params import abstract_params
@@ -35,7 +34,7 @@ from ..serve.decode import make_serve_step
 from ..train.data import batch_spec
 from ..train.optimizer import AdamWConfig
 from ..train.train_step import TrainState, make_train_step
-from .mesh import make_production_mesh
+from .mesh import make_mesh, make_production_mesh
 
 # v5e hardware constants (roofline)
 PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
@@ -165,7 +164,7 @@ def _probe_layer_cost(cfg: ModelConfig,
         if cfg.enc_dec:
             kw["n_encoder_layers"] = n
         pcfg = _dc.replace(cfg, **kw)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         stats = _lower_with(pcfg, cfg.name, pshape, mesh, shape_name)
         costs[mult] = (stats["flops_per_device"], stats["bytes_per_device"])
     d_flops = (costs[2][0] - costs[1][0]) / base
@@ -231,7 +230,7 @@ def _lower_with(cfg, arch: str, shape, mesh, shape_name: str) -> dict:
                           donate_argnums=(1,)
                           ).lower(p_sds, cache_sds, specs)
     compiled = lowered.compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     return {"compiled": compiled,
             "flops_per_device": ca.get("flops", 0.0),
             "bytes_per_device": ca.get("bytes accessed", 0.0)}

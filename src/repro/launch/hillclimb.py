@@ -27,8 +27,8 @@ import json
 import jax
 
 from ..configs import get_config
-from ..distributed.compat import cost_analysis_dict, shard_map_compat
 from .dryrun import lower_cell
+from .mesh import make_mesh, make_production_mesh
 
 CELLS = {
     "A-qwen0.5b-train": ("qwen1.5-0.5b", "train_4k"),
@@ -115,10 +115,9 @@ def lower_compressed_cell(arch: str, shape_name: str, cfg,
     from ..train.train_step import TrainState, make_compressed_train_step
     from .dryrun import (abstract_state, collective_bytes, input_specs,
                          roofline_terms, scan_corrected, state_shardings)
-    from .mesh import make_production_mesh
 
     shape = SHAPES[shape_name]
-    mesh = (jax.make_mesh((256, 1), ("data", "model")) if dp_only
+    mesh = (make_mesh((256, 1), ("data", "model")) if dp_only
             else make_production_mesh(multi_pod=False))
     state_sds, defs = abstract_state(cfg)
     # compressed DP needs the error-feedback buffer
@@ -130,9 +129,8 @@ def lower_compressed_cell(arch: str, shape_name: str, cfg,
     step_inner, data_axes = make_compressed_train_step(
         cfg, AdamWConfig(), mesh, fused=fused, two_phase=two_phase)
     bspec = PS(data_axes if len(data_axes) > 1 else data_axes[0])
-    # manual over data axes; 'model' stays auto (TP preserved) only on
-    # modern jax — shard_map_compat replicates it on jax 0.4.x
-    stepped = shard_map_compat(
+    # manual over data axes; 'model' stays auto (TP preserved)
+    stepped = jax.shard_map(
         step_inner, mesh=mesh, axis_names=set(data_axes),
         in_specs=(jax.tree.map(lambda _: PS(), state_sds),
                   jax.tree.map(lambda _: bspec, specs)),
@@ -147,7 +145,7 @@ def lower_compressed_cell(arch: str, shape_name: str, cfg,
                       in_shardings=(st_shard, batch_shardings(mesh, specs))
                       ).lower(state_sds, specs)
     compiled = lowered.compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     stats = {"arch": arch, "shape": shape_name,
              "mesh": "256x1-dp" if dp_only else "16x16",
              "n_devices": mesh.devices.size, "skipped": False,
@@ -170,7 +168,7 @@ def lower_dp_baseline(arch: str, shape_name: str, cfg) -> dict:
     from .dryrun import (abstract_state, collective_bytes, input_specs,
                          roofline_terms, scan_corrected, state_shardings)
     shape = SHAPES[shape_name]
-    mesh = jax.make_mesh((256, 1), ("data", "model"))
+    mesh = make_mesh((256, 1), ("data", "model"))
     state_sds, defs = abstract_state(cfg)
     specs = input_specs(cfg, shape)
     step = make_train_step(cfg, AdamWConfig(), loss_chunk=cfg.loss_chunk)
@@ -179,7 +177,7 @@ def lower_dp_baseline(arch: str, shape_name: str, cfg) -> dict:
                                     batch_shardings(mesh, specs)),
                       donate_argnums=(0,)).lower(state_sds, specs)
     compiled = lowered.compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     stats = {"arch": arch, "shape": shape_name, "mesh": "256x1-dp",
              "n_devices": 256, "skipped": False,
              "flops_per_device": ca.get("flops", 0.0),

@@ -16,6 +16,7 @@ from __future__ import annotations
 import argparse
 
 from ..serve.server import SimdramServer
+from .compile_cache import configure_compile_cache
 
 
 def main(argv=None) -> int:
@@ -45,6 +46,7 @@ def main(argv=None) -> int:
     ap.add_argument("--max-steps", type=int, default=None,
                     help="serving-loop step cap (default: run to drain)")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     configs = [c.strip() for c in args.config.split(",") if c.strip()]
     if not configs:

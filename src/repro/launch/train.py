@@ -19,7 +19,6 @@ from jax.sharding import PartitionSpec as PS
 
 from ..configs import get_config, get_reduced
 from ..distributed.checkpoint import CheckpointManager
-from ..distributed.compat import shard_map_compat
 from ..distributed.failover import FailoverConfig, FailoverRunner
 from ..distributed.sharding import replicated, tree_shardings
 from ..models.params import init_params
@@ -28,13 +27,15 @@ from ..train.data import DataConfig, synthetic_batch
 from ..train.optimizer import AdamWConfig, AdamWState
 from ..train.train_step import (TrainState, init_train_state,
                                 make_compressed_train_step, make_train_step)
+from .compile_cache import configure_compile_cache
+from .mesh import make_mesh
 
 
 def build_mesh(n_model: int | None = None):
     n_dev = len(jax.devices())
     n_model = n_model or (2 if n_dev % 2 == 0 and n_dev > 1 else 1)
     n_data = n_dev // n_model
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def setup(cfg, mesh, opt_cfg: AdamWConfig, compressed: bool = False,
@@ -52,11 +53,10 @@ def setup(cfg, mesh, opt_cfg: AdamWConfig, compressed: bool = False,
         step_inner, data_axes = make_compressed_train_step(
             cfg, opt_cfg, mesh, exchange=exchange)
         # manual over the data axes (explicit packed-sign collectives); the
-        # model axis stays auto where the jax version supports partial-manual
-        # (shard_map_compat replicates it on legacy jax)
+        # model axis stays under the automatic partitioner
         pspec = PS()
         bspec = PS(data_axes if len(data_axes) > 1 else data_axes[0])
-        step = shard_map_compat(
+        step = jax.shard_map(
             step_inner, mesh=mesh, axis_names=set(data_axes),
             in_specs=(jax.tree.map(lambda _: pspec, state),
                       {"tokens": bspec, "labels": bspec}),
@@ -88,6 +88,7 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     mesh = build_mesh()

@@ -170,8 +170,7 @@ def make_compressed_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
     loss_fn = make_loss_fn(cfg, loss_chunk=cfg.loss_chunk)
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    # static voter count: the mesh is known at build time (and jax 0.4.x has
-    # no jax.lax.axis_size to query it inside the traced body)
+    # static voter count: the mesh is known at build time
     n_voters = 1
     for a in data_axes:
         n_voters *= int(mesh.shape[a])

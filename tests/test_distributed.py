@@ -33,13 +33,14 @@ def test_pjit_train_step_on_4x2_mesh():
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_reduced
         from repro.distributed.sharding import tree_shardings, batch_shardings
+        from repro.launch.mesh import make_mesh
         from repro.models.params import init_params
         from repro.models.transformer import model_defs
         from repro.train.optimizer import AdamWConfig
         from repro.train.train_step import init_train_state, make_train_step
         from repro.train.data import DataConfig, synthetic_batch
         cfg = get_reduced('qwen1_5_0_5b')
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         defs = model_defs(cfg)
         sh = tree_shardings(defs, mesh)
         params = jax.tree.map(jax.device_put,
@@ -48,7 +49,7 @@ def test_pjit_train_step_on_4x2_mesh():
         step = jax.jit(make_train_step(cfg, AdamWConfig(lr=1e-3)))
         d = DataConfig(vocab=cfg.vocab, seq_len=64, global_batch=8)
         losses = []
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, 'use_mesh') else mesh:
+        with jax.set_mesh(mesh):
             for s in range(8):
                 state, m = step(state, synthetic_batch(d, s))
                 losses.append(float(m['loss']))
@@ -65,11 +66,12 @@ def test_compressed_majority_vote_training(exchange):
     out = run_with_devices(f"""
         import jax, jax.numpy as jnp
         from repro.configs import get_reduced
-        from repro.launch.train import setup, build_mesh
+        from repro.launch.mesh import make_mesh
+        from repro.launch.train import setup
         from repro.train.optimizer import AdamWConfig
         from repro.train.data import DataConfig, synthetic_batch
         cfg = get_reduced('qwen1_5_0_5b')
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         state, _, step = setup(cfg, mesh, AdamWConfig(lr=5e-3),
                                compressed=True, exchange={exchange!r})
         d = DataConfig(vocab=cfg.vocab, seq_len=64, global_batch=8)
@@ -88,7 +90,8 @@ def test_pipeline_parallel_matches_sequential():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.pipeline import pipeline_apply, stack_stage_params
-        mesh = jax.make_mesh((4, 2), ('pipe', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ('pipe', 'model'))
         P, M, mb, d = 4, 6, 8, 16
         keys = jax.random.split(jax.random.key(0), P)
         stage_params = [ {'w': jax.random.normal(k, (d, d)) * 0.3} for k in keys ]
@@ -115,11 +118,12 @@ def test_elastic_checkpoint_restore_smaller_mesh(tmp_path):
         from repro.configs import get_reduced
         from repro.distributed.sharding import tree_shardings
         from repro.distributed.checkpoint import CheckpointManager
+        from repro.launch.mesh import make_mesh
         from repro.models.params import init_params
         from repro.models.transformer import model_defs
         from repro.train.train_step import init_train_state
         cfg = get_reduced('qwen1_5_0_5b')
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         defs = model_defs(cfg)
         params = jax.tree.map(jax.device_put,
                               init_params(defs, jax.random.key(0)),
@@ -133,11 +137,12 @@ def test_elastic_checkpoint_restore_smaller_mesh(tmp_path):
         from repro.configs import get_reduced
         from repro.distributed.sharding import tree_shardings
         from repro.distributed.checkpoint import CheckpointManager
+        from repro.launch.mesh import make_mesh
         from repro.models.params import init_params
         from repro.models.transformer import model_defs
         from repro.train.train_step import init_train_state
         cfg = get_reduced('qwen1_5_0_5b')
-        mesh = jax.make_mesh((2, 2), ('data', 'model'))   # downscaled!
+        mesh = make_mesh((2, 2), ('data', 'model'))   # downscaled!
         defs = model_defs(cfg)
         like = init_train_state(init_params(defs, jax.random.key(1)))
         mgr = CheckpointManager({ck!r})
@@ -162,8 +167,8 @@ def test_two_phase_majority_vote_training():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as PS
         from repro.configs import get_reduced
-        from repro.distributed.compat import shard_map_compat
         from repro.distributed.sharding import tree_shardings
+        from repro.launch.mesh import make_mesh
         from repro.models.params import init_params
         from repro.models.transformer import model_defs
         from repro.train.optimizer import AdamWConfig
@@ -171,7 +176,7 @@ def test_two_phase_majority_vote_training():
                                             make_compressed_train_step)
         from repro.train.data import DataConfig, synthetic_batch
         cfg = get_reduced('qwen1_5_0_5b')
-        mesh = jax.make_mesh((8, 1), ('data', 'model'))
+        mesh = make_mesh((8, 1), ('data', 'model'))
         defs = model_defs(cfg)
         params = jax.tree.map(jax.device_put,
                               init_params(defs, jax.random.key(0)),
@@ -179,7 +184,7 @@ def test_two_phase_majority_vote_training():
         state = init_train_state(params, compressed=True)
         inner, da = make_compressed_train_step(cfg, AdamWConfig(lr=5e-3),
                                                mesh, two_phase=True)
-        step = jax.jit(shard_map_compat(
+        step = jax.jit(jax.shard_map(
             inner, mesh=mesh, axis_names={'data'},
             in_specs=(jax.tree.map(lambda _: PS(), state),
                       {'tokens': PS('data'), 'labels': PS('data')}),
@@ -206,7 +211,8 @@ def test_pipeline_parallel_is_differentiable():
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.pipeline import (pipeline_apply,
                                                 stack_stage_params)
-        mesh = jax.make_mesh((4, 2), ('pipe', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ('pipe', 'model'))
         P, M, mb, d = 4, 4, 4, 8
         keys = jax.random.split(jax.random.key(0), P)
         stages = [{'w': jax.random.normal(k, (d, d)) * 0.3} for k in keys]
