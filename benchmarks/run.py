@@ -1,6 +1,5 @@
 # One function per paper table. Print ``name,us_per_call,derived`` CSV.
-"""Benchmark driver: one module per paper table/figure (+ the roofline
-table from the multi-pod dry-run artifacts).
+"""Benchmark driver: one module per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run [--only fig9,table3,...]
 
@@ -44,8 +43,8 @@ from repro.launch.compile_cache import configure_compile_cache
 
 from . import (bench_apps, bench_area, bench_data_movement,
                bench_dualitycache, bench_energy, bench_reliability,
-               bench_roofline, bench_serving, bench_table5_counts,
-               bench_throughput, bench_transposition)
+               bench_serving, bench_table5_counts, bench_throughput,
+               bench_transposition)
 from .common import _KV, bad_gate_rows, bad_perf_values
 
 BENCHES = {
@@ -58,7 +57,6 @@ BENCHES = {
     "fig13": bench_data_movement.main,       # Fig. 13  data movement
     "fig14": bench_transposition.main,       # Fig. 14  transposition
     "area": bench_area.main,                 # §7.8     area
-    "roofline": bench_roofline.main,         # §Roofline (ours)
     "serving": bench_serving.main,           # §Serving (ours)
 }
 

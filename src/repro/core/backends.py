@@ -52,6 +52,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..simdram.layout import (LANE_WORD, register_movement_hook,
                               register_transpose_hook)
@@ -783,41 +784,46 @@ def execute_lowered(prog: UProgram, trace: LoweredTrace, operands: dict,
     banks = first.shape[0] if banked else 1
     eff = machine if machine is not None else _current_machine()
     charging = _charging_stats(eff)
-    for st in charging:
-        offsets = None
-        for planes in operands.values():
-            if id(planes) in st._resident:
-                # direct reuse of a prior op's output planes stays inside
-                # the bank: an intra-bank LISA relocation per result row.
-                # Inter-bank PSM traffic is charged where it actually
-                # happens — BitplaneArray.rebank via the layout movement
-                # hooks (bank layouts cannot silently change between an
-                # op's output and a consumer's operand; rebank creates a
-                # new array).
-                st.charge_movement(int(planes.shape[-2]))
-            skew = st.take_bank_skew(id(planes), banks, machine=eff)
-            if skew is not None:
-                # this op consumes freshly scattered planes: its per-bank
-                # streams cannot start before each bank's data arrived
-                # (two scattered operands gate on the later arrival)
-                offsets = skew if offsets is None else tuple(
-                    max(a, b) for a, b in zip(offsets, skew))
-        st.charge_program(prog, banks, int(first.shape[-1]) * LANE_WORD,
-                          trace=trace, offsets=offsets)
-    if banked:                   # bank axis: one subarray per bank
-        if not getattr(fn, "jax_traceable", True):
-            # non-traceable backends (numpy oracle) iterate banks instead
-            per = [fn(trace, {k: v[i] for k, v in operands.items()},
-                      out_bits=out_bits) for i in range(banks)]
-            outs = {k: jnp.stack([p[k] for p in per]) for k in per[0]}
+    mode = "+".join(sorted({st.mode for st in charging})) or "none"
+    with TraceAnnotation("simdram.charge", mode=mode):
+        for st in charging:
+            offsets = None
+            for planes in operands.values():
+                if id(planes) in st._resident:
+                    # direct reuse of a prior op's output planes stays inside
+                    # the bank: an intra-bank LISA relocation per result row.
+                    # Inter-bank PSM traffic is charged where it actually
+                    # happens — BitplaneArray.rebank via the layout movement
+                    # hooks (bank layouts cannot silently change between an
+                    # op's output and a consumer's operand; rebank creates a
+                    # new array).
+                    st.charge_movement(int(planes.shape[-2]))
+                skew = st.take_bank_skew(id(planes), banks, machine=eff)
+                if skew is not None:
+                    # this op consumes freshly scattered planes: its per-bank
+                    # streams cannot start before each bank's data arrived
+                    # (two scattered operands gate on the later arrival)
+                    offsets = skew if offsets is None else tuple(
+                        max(a, b) for a, b in zip(offsets, skew))
+            st.charge_program(prog, banks, int(first.shape[-1]) * LANE_WORD,
+                              trace=trace, offsets=offsets)
+    with TraceAnnotation("simdram.execute", backend=backend or _DEFAULT,
+                         n_cmds=len(trace.cmds), banks=banks):
+        if banked:                   # bank axis: one subarray per bank
+            if not getattr(fn, "jax_traceable", True):
+                # non-traceable backends (numpy oracle) iterate banks instead
+                per = [fn(trace, {k: v[i] for k, v in operands.items()},
+                          out_bits=out_bits) for i in range(banks)]
+                outs = {k: jnp.stack([p[k] for p in per]) for k in per[0]}
+            else:
+                outs = jax.vmap(lambda ops: fn(trace, ops, out_bits=out_bits)
+                                )(operands)
         else:
-            outs = jax.vmap(lambda ops: fn(trace, ops, out_bits=out_bits)
-                            )(operands)
-    else:
-        outs = fn(trace, operands, out_bits=out_bits)
-    for st in charging:
-        for arr in outs.values():
-            st.note_output(arr)
+            outs = fn(trace, operands, out_bits=out_bits)
+    with TraceAnnotation("simdram.charge", mode=mode):
+        for st in charging:
+            for arr in outs.values():
+                st.note_output(arr)
     return outs
 
 

@@ -47,6 +47,7 @@ import threading
 import weakref
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .uprogram import (AAP, AP, C0, C1, CRow, DRow, N_B_CELLS, Port,
                        UProgram, normalize_uop)
@@ -502,10 +503,11 @@ class TraceCache:
                     hit[1].lint().raise_for_errors()
                 return hit
             self._misses += 1
-            prog = self._compile(name, n_bits, bool(optimize))
-            trace = lower_program(prog)
-            if self.verify if verify is None else verify:
-                trace.lint().raise_for_errors()
+            with TraceAnnotation("simdram.compile", op=name, n_bits=n_bits):
+                prog = self._compile(name, n_bits, bool(optimize))
+                trace = lower_program(prog)
+                if self.verify if verify is None else verify:
+                    trace.lint().raise_for_errors()
             entry = (prog, trace)
             self._entries[key] = entry
             while self.capacity is not None and \

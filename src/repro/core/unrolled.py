@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from .uprogram import AAP, AP, CRow, DRow, Port, UProgram
 
@@ -76,13 +77,14 @@ def run_trace_unrolled(trace, operands: dict[str, jax.Array],
     ``reference`` decoder instead of re-walking μOp dataclasses per call.
     """
     words = next(iter(operands.values())).shape[1]
-    zero = jnp.zeros((words,), jnp.uint32)
-    rows: list = [zero] * trace.n_rows
-    for key in trace.d_rows:
-        arr, bit = key
-        if arr in operands and bit < operands[arr].shape[0]:
-            rows[trace.row_index[key] - 1] = operands[arr][bit]
-    rows[trace.row_index["C1"] - 1] = jnp.full((words,), FULL)
+    with TraceAnnotation("simdram.rows_in"):
+        zero = jnp.zeros((words,), jnp.uint32)
+        rows: list = [zero] * trace.n_rows
+        for key in trace.d_rows:
+            arr, bit = key
+            if arr in operands and bit < operands[arr].shape[0]:
+                rows[trace.row_index[key] - 1] = operands[arr][bit]
+        rows[trace.row_index["C1"] - 1] = jnp.full((words,), FULL)
 
     def read(i: int):
         v = rows[-i - 1 if i < 0 else i - 1]
@@ -92,19 +94,22 @@ def run_trace_unrolled(trace, operands: dict[str, jax.Array],
         rows[-i - 1 if i < 0 else i - 1] = \
             (~val).astype(jnp.uint32) if i < 0 else val
 
-    for op, a, b, c in trace.cmds.tolist():
-        if op == 1:                      # MAJ (AP / fused-AAP first activate)
-            res = _maj(read(a), read(b), read(c))
-            write(a, res)
-            write(b, res)
-            write(c, res)
-        else:                            # COPY (one AAP destination)
-            write(a, read(b))
+    with TraceAnnotation("simdram.commands"):
+        for op, a, b, c in trace.cmds.tolist():
+            if op == 1:                  # MAJ (AP / fused-AAP first activate)
+                res = _maj(read(a), read(b), read(c))
+                write(a, res)
+                write(b, res)
+                write(c, res)
+            else:                        # COPY (one AAP destination)
+                write(a, read(b))
     out_bits = out_bits or {}
     outs = {}
-    for name in trace.outputs:
-        nb = out_bits.get(name, trace.n_bits)
-        outs[name] = jnp.stack([rows[i] for i in trace.out_row_ids(name, nb)])
+    with TraceAnnotation("simdram.rows_out"):
+        for name in trace.outputs:
+            nb = out_bits.get(name, trace.n_bits)
+            outs[name] = jnp.stack(
+                [rows[i] for i in trace.out_row_ids(name, nb)])
     return outs
 
 

@@ -73,6 +73,7 @@ def bitplane_transpose(groups: jax.Array,
         in_specs=[pl.BlockSpec((GROUP, LANE_BLOCK), lambda i: (0, i))],
         out_specs=pl.BlockSpec((GROUP, LANE_BLOCK), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((GROUP, g), jnp.uint32),
+        name="bitplane_transpose",
         interpret=interpret_mode() if interpret is None else interpret,
     )(x)
     return out

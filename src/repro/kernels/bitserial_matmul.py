@@ -69,6 +69,7 @@ def bitserial_matmul(a_packed: jax.Array, b_packed: jax.Array, k_bits: int,
                   pl.BlockSpec((bn, bk), lambda i, j, k: (j, k))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        name="bitserial_matmul",
         interpret=interpret_mode() if interpret is None else interpret,
     )(a_packed, b_packed)
     return k_bits - 2 * mismatches

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..core.trace import LoweredTrace, lower_program
 from ..core.uprogram import UProgram
@@ -33,27 +34,30 @@ def run_trace_kernel(trace: LoweredTrace, operands: dict[str, jax.Array],
     follows :func:`repro.kernels.interpret_mode`.
     """
     words = next(iter(operands.values())).shape[1]
-    zero = jnp.zeros((words,), jnp.uint32)
-    planes: list = [zero] * trace.n_rows
-    for key in trace.d_rows:
-        arr, bit = key
-        if arr in operands and bit < operands[arr].shape[0]:
-            planes[trace.row_index[key] - 1] = operands[arr][bit]
-    c1_row = trace.row_index["C1"] - 1
-    planes[c1_row] = jnp.full((words,), jnp.uint32(0xFFFFFFFF))
-    rows = jnp.stack(planes)
-    pad = (-words) % 128
-    if pad:
-        rows = jnp.pad(rows, ((0, 0), (0, pad)))
-        rows = rows.at[c1_row, words:].set(jnp.uint32(0xFFFFFFFF))
-    cmds = jnp.asarray(trace.cmds, jnp.int32)
-    final = uprog_execute(cmds, rows, interpret=interpret)
-    final = final[:, :words]
+    with TraceAnnotation("simdram.rows_in"):
+        zero = jnp.zeros((words,), jnp.uint32)
+        planes: list = [zero] * trace.n_rows
+        for key in trace.d_rows:
+            arr, bit = key
+            if arr in operands and bit < operands[arr].shape[0]:
+                planes[trace.row_index[key] - 1] = operands[arr][bit]
+        c1_row = trace.row_index["C1"] - 1
+        planes[c1_row] = jnp.full((words,), jnp.uint32(0xFFFFFFFF))
+        rows = jnp.stack(planes)
+        pad = (-words) % 128
+        if pad:
+            rows = jnp.pad(rows, ((0, 0), (0, pad)))
+            rows = rows.at[c1_row, words:].set(jnp.uint32(0xFFFFFFFF))
+        cmds = jnp.asarray(trace.cmds, jnp.int32)
+    with TraceAnnotation("simdram.commands"):
+        final = uprog_execute(cmds, rows, interpret=interpret)
     out_bits = out_bits or {}
     outs = {}
-    for name in trace.outputs:
-        nb = out_bits.get(name, trace.n_bits)
-        outs[name] = final[jnp.array(trace.out_row_ids(name, nb))]
+    with TraceAnnotation("simdram.rows_out"):
+        final = final[:, :words]
+        for name in trace.outputs:
+            nb = out_bits.get(name, trace.n_bits)
+            outs[name] = final[jnp.array(trace.out_row_ids(name, nb))]
     return outs
 
 

@@ -85,6 +85,7 @@ def uprog_execute(cmds: jax.Array, rows: jax.Array,
             out_specs=pl.BlockSpec((r, LANE_BLOCK), lambda i, cmds: (0, i)),
         ),
         out_shape=jax.ShapeDtypeStruct((r, w), jnp.uint32),
+        name="uprog_execute",
         interpret=interpret_mode() if interpret is None else interpret,
     )(cmds.reshape(-1), rows)
 

@@ -24,6 +24,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 LANE_WORD = 32  # lanes packed per uint32 word
 
@@ -125,11 +126,12 @@ def to_bitplanes(values: jax.Array, n_bits: int) -> jax.Array:
     TRANSPOSE_STATS["to_bitplanes"] += 1
     for hook in _PERF_HOOKS:
         hook("to", n_bits, e)
-    u = values.astype(jnp.uint32)
-    bits = (u[None, :] >> jnp.arange(n_bits, dtype=jnp.uint32)[:, None]) & 1
-    bits = bits.reshape(n_bits, e // LANE_WORD, LANE_WORD)
-    shifts = jnp.arange(LANE_WORD, dtype=jnp.uint32)
-    return (bits << shifts).sum(axis=-1, dtype=jnp.uint32)
+    with TraceAnnotation("simdram.layout", dir="to", n_bits=n_bits, lanes=e):
+        u = values.astype(jnp.uint32)
+        bits = (u[None, :] >> jnp.arange(n_bits, dtype=jnp.uint32)[:, None]) & 1
+        bits = bits.reshape(n_bits, e // LANE_WORD, LANE_WORD)
+        shifts = jnp.arange(LANE_WORD, dtype=jnp.uint32)
+        return (bits << shifts).sum(axis=-1, dtype=jnp.uint32)
 
 
 def from_bitplanes(planes: jax.Array, signed: bool = False,
@@ -139,16 +141,19 @@ def from_bitplanes(planes: jax.Array, signed: bool = False,
     n_bits, w = planes.shape
     for hook in _PERF_HOOKS:
         hook("from", n_bits, w * LANE_WORD)
-    shifts = jnp.arange(LANE_WORD, dtype=jnp.uint32)
-    bits = (planes[:, :, None] >> shifts) & 1          # (n_bits, W, 32)
-    bits = bits.reshape(n_bits, w * LANE_WORD)
-    weights = (jnp.uint32(1) << jnp.arange(n_bits, dtype=jnp.uint32))
-    val = (bits.astype(jnp.uint32) * weights[:, None]).sum(0, dtype=jnp.uint32)
-    if signed and n_bits < 32:
-        sign = (val >> np.uint32(n_bits - 1)) & 1
-        val = val.astype(dtype) - (sign << n_bits).astype(dtype)
-        return val
-    return val.astype(dtype)
+    with TraceAnnotation("simdram.layout", dir="from", n_bits=n_bits,
+                         lanes=w * LANE_WORD):
+        shifts = jnp.arange(LANE_WORD, dtype=jnp.uint32)
+        bits = (planes[:, :, None] >> shifts) & 1          # (n_bits, W, 32)
+        bits = bits.reshape(n_bits, w * LANE_WORD)
+        weights = (jnp.uint32(1) << jnp.arange(n_bits, dtype=jnp.uint32))
+        val = (bits.astype(jnp.uint32) * weights[:, None]).sum(
+            0, dtype=jnp.uint32)
+        if signed and n_bits < 32:
+            sign = (val >> np.uint32(n_bits - 1)) & 1
+            val = val.astype(dtype) - (sign << n_bits).astype(dtype)
+            return val
+        return val.astype(dtype)
 
 
 def pack_mask(mask: jax.Array) -> jax.Array:

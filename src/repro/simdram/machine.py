@@ -66,6 +66,7 @@ import itertools
 import threading
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..core.backends import (PerfStats, execute_heterogeneous,
                              execute_lowered)
@@ -156,7 +157,9 @@ class BoundOp:
                  signed_out: bool = False, optimize: bool = True,
                  backend: str | None = None):
         from ..ops.bbops import _run_op
-        with self.machine.session():
+        with TraceAnnotation("simdram.op", op=self.name, n_bits=n_bits,
+                             banks=self.machine.banks), \
+                self.machine.session():
             # one μProgram-Memory access per call (the fetched pair rides
             # through to execution), and operand layout conversion happens
             # inside the session so the machine's scoped hooks observe the

@@ -87,3 +87,23 @@ def test_bitserial_matmul_compiles_with_default_blocks(one_chip):
     _assert_kernel(fn.lower(_sds((m, k // 32), jnp.uint32, one_chip),
                             _sds((n, k // 32), jnp.uint32, one_chip))
                    .compile())
+
+
+@pytest.mark.parametrize("kernel", ["uprog_execute", "bitplane_transpose",
+                                    "bitserial_matmul"])
+def test_kernel_names_are_stable(one_chip, kernel):
+    """Each kernel's TPU custom call carries the kernel's own name, so a
+    profile finds it by name."""
+    if kernel == "uprog_execute":
+        trace = lower_program(compile_operation("addition", 8))
+        fn = jax.jit(lambda c, r: uprog_execute(c, r, interpret=False))
+        args = (_sds(trace.cmds.shape, jnp.int32, one_chip),
+                _sds((trace.n_rows, WORDS), jnp.uint32, one_chip))
+    elif kernel == "bitplane_transpose":
+        fn = jax.jit(lambda g: bitplane_transpose(g, interpret=False))
+        args = (_sds((WORDS, 32), jnp.uint32, one_chip),)
+    else:
+        fn = jax.jit(lambda a, b: bitserial_matmul(a, b, 4096,
+                                                   interpret=False))
+        args = (_sds((1024, 128), jnp.uint32, one_chip),) * 2
+    assert f'kernel_name = "{kernel}"' in fn.lower(*args).as_text()
