@@ -11,9 +11,10 @@ Registered backends:
 * ``reference`` — decodes the trace back to μOps and runs them on the
   faithful numpy :class:`~repro.core.executor.Subarray` model: exact
   AAP/AP semantics, destructive TRAs, DCC ports.  The oracle.
-* ``unrolled``  — scans the trace's command array at trace time into pure
-  jnp dataflow (:func:`repro.core.unrolled.run_trace_unrolled`): copies
-  vanish, constants fold; the TPU-native fast path.  jit/vmap-compatible.
+* ``unrolled``  — runs the whole trace as one compiled program
+  (:func:`repro.core.unrolled.run_trace_unrolled`): the command array under
+  ``lax.scan`` over a row file that holds every bank, compiled once per
+  shape; the TPU-native fast path.  jit/vmap-compatible.
 * ``pallas``    — the Fig.-7 control-unit FSM as a Pallas kernel
   (:func:`repro.kernels.ops.run_trace_kernel`): the trace's int32 command
   stream driving a VMEM row file, compiled on a TPU and interpreted on the
@@ -58,9 +59,13 @@ from ..simdram.layout import (LANE_WORD, register_movement_hook,
                               register_transpose_hook)
 from ..simdram.timing import SimdramPerfModel
 from .trace import GLOBAL_TRACE_CACHE, LoweredTrace, lower_program
+from .unrolled import run_trace_unrolled
 from .uprogram import UProgram
 
-# backend: (trace, operands: dict[str, uint32[n_bits, W]], out_bits) → outputs
+# backend: (trace, operands: dict[str, uint32[n_bits, W]], out_bits) → outputs.
+# A backend may carry ``fn.bind(trace, operands, out_bits)``: it then takes
+# banked operands itself and returns a call whose ``compiles`` flag says
+# whether running it compiles a program (the ``unrolled`` backend)
 BackendFn = Callable[..., dict]
 
 _REGISTRY: dict[str, BackendFn] = {}
@@ -752,7 +757,8 @@ register_movement_hook(_movement_hook)
 def execute_program(prog: UProgram, operands: dict, out_bits=None,
                     backend: str | None = None) -> dict:
     """Lower a μProgram to its command trace (memoized) and dispatch it to
-    a backend; banked operands vmap over banks.
+    a backend; banked operands run every bank in one program (``unrolled``),
+    vmap over banks (``pallas``) or loop over them (``reference``).
 
     ``operands``: name → uint32[n_bits, W] or uint32[banks, n_bits, W];
     all operands must agree on bankedness.  Returns planes with a matching
@@ -807,9 +813,16 @@ def execute_lowered(prog: UProgram, trace: LoweredTrace, operands: dict,
                         max(a, b) for a, b in zip(offsets, skew))
             st.charge_program(prog, banks, int(first.shape[-1]) * LANE_WORD,
                               trace=trace, offsets=offsets)
+    # a backend that binds takes banked operands directly and says whether
+    # the call compiles its program
+    bind = getattr(fn, "bind", None)
+    call = bind(trace, operands, out_bits=out_bits) if bind else None
+    compiled = {} if call is None else {"compiled": int(call.compiles)}
     with TraceAnnotation("simdram.execute", backend=backend or _DEFAULT,
-                         n_cmds=len(trace.cmds), banks=banks):
-        if banked:                   # bank axis: one subarray per bank
+                         n_cmds=len(trace.cmds), banks=banks, **compiled):
+        if call is not None:
+            outs = call()
+        elif banked:                 # bank axis: one subarray per bank
             if not getattr(fn, "jax_traceable", True):
                 # non-traceable backends (numpy oracle) iterate banks instead
                 per = [fn(trace, {k: v[i] for k, v in operands.items()},
@@ -838,7 +851,7 @@ def execute_heterogeneous(items, machine=None) -> list:
     order.  Adjacent items that share the same trace, backend, out_bits
     and operand layout are *stacked along the bank axis* and dispatched as
     one banked :func:`execute_lowered` call — a tenant's stream of
-    identical requests collapses into a handful of vmapped executions
+    identical requests collapses into a handful of banked executions
     instead of one dispatch per request, exactly the bank-parallel
     placement the scheduler models.  The modeled charge is the banked
     charge (latency once, energy × the stacked width); per-request timing
@@ -880,12 +893,6 @@ def execute_heterogeneous(items, machine=None) -> list:
 # ---------------------------------------------------------------------------
 # Built-in backends
 # ---------------------------------------------------------------------------
-
-
-def _unrolled_execute(trace: LoweredTrace, operands: dict,
-                      out_bits=None) -> dict:
-    from .unrolled import run_trace_unrolled
-    return run_trace_unrolled(trace, operands, out_bits=out_bits)
 
 
 def _pallas_execute(trace: LoweredTrace, operands: dict,
@@ -931,6 +938,6 @@ def _reference_execute(trace: LoweredTrace, operands: dict,
 
 _reference_execute.jax_traceable = False
 
-register_backend("unrolled", _unrolled_execute)
+register_backend("unrolled", run_trace_unrolled)
 register_backend("pallas", _pallas_execute)
 register_backend("reference", _reference_execute)

@@ -212,6 +212,11 @@ class LoweredTrace:
     _lint: object = dataclasses.field(default=None, repr=False)
     _fingerprint: object = dataclasses.field(default=None, repr=False)
     _act_struct: object = dataclasses.field(default=None, repr=False)
+    # the compiled executor's arguments on the device (see
+    # repro.core.unrolled.bind), made once per trace; init=False, so a
+    # dataclasses.replace copy with other commands starts without them
+    _device: dict = dataclasses.field(default_factory=dict, init=False,
+                                      repr=False, compare=False)
 
     @property
     def n_rows(self) -> int:

@@ -5,7 +5,7 @@ command trace (:class:`~repro.core.trace.LoweredTrace`): build a row file
 (D rows + C rows + B cells) straight from the trace's row-index map, run
 its int32 command array in the VMEM FSM kernel, read outputs back.  It is
 semantically identical to ``repro.core.unrolled.run_trace_unrolled`` (the
-trace-time path) and the decoded ``repro.core.executor`` run (the numpy
+compiled command scan) and the decoded ``repro.core.executor`` run (the numpy
 reference) — tests assert all three agree.  ``run_uprogram_kernel`` keeps
 the μProgram-level entry point by lowering first (memoized).
 """

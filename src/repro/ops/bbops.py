@@ -17,10 +17,11 @@ operands vertical in one batched transposition pass, keeps every
 intermediate plane-resident, and stores results back horizontal in one pass.
 
 Execution dispatches through the backend registry
-(:mod:`repro.core.backends`): ``unrolled`` (trace-time jnp, default),
-``pallas`` (the Fig.-7 control-unit FSM kernel), ``reference`` (the numpy
-``Subarray`` oracle).  Select per call (``backend="pallas"``), per scope
-(``with use_backend(...)``), or process-wide (``set_default_backend``).
+(:mod:`repro.core.backends`): ``unrolled`` (one compiled program per call,
+the default), ``pallas`` (the Fig.-7 control-unit FSM kernel),
+``reference`` (the numpy ``Subarray`` oracle).  Select per call
+(``backend="pallas"``), per scope (``with use_backend(...)``), or
+process-wide (``set_default_backend``).
 μPrograms are compiled once per (operation, element-width) and cached — the
 paper's μProgram Memory/Scratchpad behavior.
 """

@@ -177,7 +177,7 @@ class ContinuousBatcher:
     session's priority as its latency class) and drains with
     ``batch=True``: compatible sessions (equal
     :attr:`RequestProfile.batch_key`) stack along the bank axis into one
-    scheduler request + one vmapped dispatch; incompatible ones still
+    scheduler request + one banked dispatch; incompatible ones still
     pack the same rank under FR-FCFS.  The modeled clock advances by the
     step's makespan, finished sessions retire, and the server admits new
     arrivals before the next step — never mid-step, matching the

@@ -659,7 +659,7 @@ class SimdramMachine:
         uses: *compatible* submissions — same lowered trace, backend,
         out_bits and unbanked operand shape — are stacked along the bank
         axis and issued as ONE bank-parallel request (one scheduler entry,
-        one vmapped dispatch) instead of one request per submission, in
+        one banked dispatch) instead of one request per submission, in
         chunks of the controller's bank count.  All riders of a stack
         share its :class:`RequestTiming`; per-tenant attribution switches
         to fractional bank shares
@@ -757,7 +757,7 @@ class SimdramMachine:
 
     def _drain_batched(self, subs, scheduler) -> list:
         """Continuous-batching drain: stack compatible submissions along
-        the bank axis into one scheduler request + one vmapped dispatch
+        the bank axis into one scheduler request + one banked dispatch
         (see :meth:`drain` with ``batch=True``).  Returns
         ``[(future, rid), ...]``."""
         resolved = []

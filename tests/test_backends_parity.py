@@ -75,6 +75,82 @@ def test_unary_parity_vs_reference(op, n_bits, backend):
     np.testing.assert_array_equal(got, UNARY_EXPECT[op](a, n_bits))
 
 
+def _run_planes(op, n_bits, backend, planes, out_bits=None):
+    prog = compile_bbop(op, n_bits)
+    ob = None if out_bits is None else {prog.outputs[0]: out_bits}
+    return np.asarray(execute_program(prog, planes, out_bits=ob,
+                                      backend=backend)[prog.outputs[0]])
+
+
+@pytest.mark.parametrize("op", sorted(BINARY_EXPECT))
+def test_banked_parity_vs_reference(op):
+    """Banked operands (3 banks, each its own values) run every bank in one
+    compiled program; the reference runs bank by bank."""
+    n_bits = 8
+    planes = {}
+    for name in ("a", "b"):
+        banks = [planes_of(jnp.asarray(v, jnp.int32), n_bits)[0]
+                 for v in (_operands(n_bits)[0] for _ in range(3))]
+        planes[name] = jnp.stack(banks)
+    got = _run_planes(op, n_bits, "unrolled", planes)
+    assert got.shape[0] == 3
+    np.testing.assert_array_equal(
+        got, _run_planes(op, n_bits, "reference", planes))
+
+
+@pytest.mark.parametrize("n_bits", [8, 16])
+def test_narrow_out_bits_parity_vs_reference(n_bits):
+    """``out_bits`` narrower than ``n_bits``: ``greater_equal`` to 1 bit."""
+    a, b = _operands(n_bits)
+    planes = {"a": planes_of(jnp.asarray(a, jnp.int32), n_bits)[0],
+              "b": planes_of(jnp.asarray(b, jnp.int32), n_bits)[0]}
+    got = _run_planes("greater_equal", n_bits, "unrolled", planes, out_bits=1)
+    assert got.shape[0] == 1
+    np.testing.assert_array_equal(
+        got, _run_planes("greater_equal", n_bits, "reference", planes,
+                         out_bits=1))
+    np.testing.assert_array_equal(
+        np.asarray(values_of(jnp.asarray(got), N)), (a >= b).astype(np.int64))
+
+
+def test_multiplication_32_parity_vs_reference():
+    """The 6,928-command 32-bit multiply: one scan, compiled in constant
+    time, where a trace unrolled under ``jit`` would not compile."""
+    a, b = (RNG.integers(0, 1 << 31, N, dtype=np.int64) for _ in range(2))
+    planes = {"a": planes_of(jnp.asarray(a, jnp.uint32), 32)[0],
+              "b": planes_of(jnp.asarray(b, jnp.uint32), 32)[0]}
+    got = _run_planes("multiplication", 32, "unrolled", planes)
+    np.testing.assert_array_equal(
+        got, _run_planes("multiplication", 32, "reference", planes))
+    np.testing.assert_array_equal(
+        np.asarray(values_of(jnp.asarray(got), N)).astype(np.uint32),
+        (a * b).astype(np.uint32))
+
+
+def test_unrolled_inside_jit_and_vmap():
+    """Inside an outer ``jit`` or ``vmap`` the compiled executor is traced
+    into the caller's program.  A trace first run under ``jit`` keeps
+    concrete device data, so later eager calls of it still run."""
+    import jax
+    from repro.core.circuits import compile_operation
+    a, b = _operands(8)
+    pa, pb = (planes_of(jnp.asarray(v, jnp.int32), 8)[0] for v in (a, b))
+    prog = compile_operation("addition", 8)       # a trace never run yet
+
+    def add(x, y):
+        return execute_program(prog, {"a": x, "b": y},
+                               backend="unrolled")[prog.outputs[0]]
+
+    want = np.asarray(BINARY_EXPECT["addition"](a, b, 255))
+    got = np.asarray(jax.jit(add)(pa, pb))
+    np.testing.assert_array_equal(np.asarray(values_of(got, N)), want)
+    stacked = np.asarray(jax.vmap(add)(jnp.stack([pa, pb]),
+                                       jnp.stack([pb, pa])))
+    np.testing.assert_array_equal(stacked[0], got)
+    np.testing.assert_array_equal(stacked[1], got)
+    np.testing.assert_array_equal(np.asarray(add(pa, pb)), got)
+
+
 def test_registry_surface():
     assert {"reference", "unrolled", "pallas"} <= set(list_backends())
     assert callable(get_backend("pallas"))

@@ -16,6 +16,7 @@ Covers the tentpole acceptance criteria:
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.circuits import (compile_bitcount, list_operations, rebase,
@@ -24,8 +25,10 @@ from repro.core.circuits import (compile_bitcount, list_operations, rebase,
 from repro.core.compiler import compile_slice
 from repro.core.graph import lit_not
 from repro.core.trace import TraceCache, canonical_uops
+from repro.core.unrolled import EXECUTABLES
 from repro.core.uprogram import DRow, concat_programs
 from repro.ops import SimdramMachine, bbop_add, current_machine
+from repro.simdram.layout import BitplaneArray
 from repro.simdram.machine import default_machine
 from repro.simdram.timing import DRAMTiming
 
@@ -285,6 +288,67 @@ def test_bound_op_counts_one_cache_access_per_call():
     st = m.cache_stats()
     assert (st["hits"], st["misses"]) == (1, 1)
     assert st["hit_rate"] == pytest.approx(0.5)
+
+
+class _Compiles:
+    """Counts XLA compilations while it is open."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self) -> None:
+        self.n = 0
+
+    def __call__(self, event: str, _secs: float, **_kw) -> None:
+        if event == self.EVENT:
+            self.n += 1
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        jax.monitoring.unregister_event_duration_listener(self)
+
+
+@pytest.mark.parametrize("op", ["addition", "multiplication"])
+def test_second_call_of_a_shape_compiles_nothing(op):
+    """The ``unrolled`` program compiles on a shape's first call only; the
+    second call is a hit of the executable store and compiles nothing."""
+    m = SimdramMachine(banks=2, backend="unrolled")
+    x, y = (jnp.asarray(RNG.integers(0, 256, (2, N)), jnp.int32)
+            for _ in range(2))
+    jax.block_until_ready(m.op(op)(x, y, n_bits=8))
+    hits = EXECUTABLES.stats()["hits"]
+    with _Compiles() as compiles:
+        out = m.op(op)(x, y, n_bits=8)
+        jax.block_until_ready(out)
+    assert compiles.n == 0
+    assert EXECUTABLES.stats()["hits"] == hits + 1
+    want = (np.asarray(x) + np.asarray(y)) if op == "addition" \
+        else (np.asarray(x) * np.asarray(y))
+    np.testing.assert_array_equal(np.asarray(out), want & 255)
+
+
+def _charged_sequence(backend) -> dict:
+    """add and mul on values, then ``greater_equal`` on plane-resident
+    operands (one of them the add's output planes), under a timed scope."""
+    rng = np.random.default_rng(7)
+    a, b = (jnp.asarray(rng.integers(0, 256, (2, N)), jnp.int32)
+            for _ in range(2))
+    m = SimdramMachine(banks=2, backend=backend)
+    with m.timed() as st:
+        m.op("addition")(a, b, n_bits=8)
+        m.op("multiplication")(a, b, n_bits=8)
+        pa, pb = (BitplaneArray.from_values(v, 8) for v in (a, b))
+        s = m.op("addition")(pa, pb, n_bits=8)
+        m.op("greater_equal")(s, pb, n_bits=8, out_bits=1)
+    return st.snapshot()
+
+
+def test_compiled_executor_charges_as_the_reference():
+    """Charging stays on the host around the dispatch: the same calls give
+    the same ``PerfStats`` snapshot on ``unrolled`` as on ``reference``."""
+    assert _charged_sequence("unrolled") == _charged_sequence("reference")
 
 
 def test_machine_timed_rejects_mode_mismatch_with_explicit_stats():

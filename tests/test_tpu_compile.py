@@ -1,9 +1,11 @@
-"""The Pallas kernels of the main path compile for a TPU v5e at real widths.
+"""The kernels and the compiled executor of the main path compile for a TPU
+v5e at real widths.
 
 Nothing here runs on a chip: each test compiles for a described (not
 attached) ``v5e:2x2`` topology and checks that the program holds a compiled
-TPU kernel (``tpu_custom_call``), so a kernel the TPU compiler refuses
-fails here instead of on the chip.  The topology is described inside a
+TPU kernel (``tpu_custom_call``), or for the executor that its command loop
+copies no row file, so a kernel the TPU compiler refuses fails here
+instead of on the chip.  The topology is described inside a
 fixture, never at import: only the worker that runs this file loads the
 TPU compiler.
 """
@@ -14,6 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.circuits import compile_operation
 from repro.core.trace import lower_program
+from repro.core.unrolled import simdram_execute
 from repro.kernels.bitplane_transpose import bitplane_transpose
 from repro.kernels.bitserial_matmul import bitserial_matmul
 from repro.kernels.ops import run_trace_kernel
@@ -107,3 +110,24 @@ def test_kernel_names_are_stable(one_chip, kernel):
                                                    interpret=False))
         args = (_sds((1024, 128), jnp.uint32, one_chip),) * 2
     assert f'kernel_name = "{kernel}"' in fn.lower(*args).as_text()
+
+
+@pytest.mark.parametrize("op,n_bits", [("addition", 16),
+                                       ("multiplication", 32)])
+def test_simdram_execute_keeps_one_row_file(one_chip, op, n_bits):
+    """The ``unrolled`` backend's program compiles at real widths and its
+    command loop updates the row file in place: the program holds no copy
+    of the whole ``uint32[n_rows, banks, W]`` row file."""
+    trace = lower_program(compile_operation(op, n_bits))
+    planes = tuple(_sds((BANKS, n_bits, WORDS), jnp.uint32, one_chip)
+                   for _ in dict.fromkeys(trace.inputs))
+    args = (planes, _sds((trace.n_rows,), jnp.int32, one_chip),
+            _sds((len(trace.cmds), 6), jnp.int32, one_chip),
+            {name: _sds((n_bits,), jnp.int32, one_chip)
+             for name in trace.outputs})
+    text = simdram_execute.lower(*args).compile().as_text()
+    assert " while(" in text
+    row_file = f"u32[{trace.n_rows},{BANKS},{WORDS}]"
+    copies = [line for line in text.splitlines()
+              if f"= {row_file}" in line and " copy(" in line]
+    assert copies == []

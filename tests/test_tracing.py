@@ -3,9 +3,11 @@
 Each case records a CPU profile around one call of a two-bank machine at
 4,096 lanes and reads the ``simdram.*`` events back from the host plane,
 as a profile of a chip run holds them: the entry span ``simdram.op``
-holds ``simdram.layout``, ``simdram.charge`` and ``simdram.execute``, and
-``simdram.execute`` holds ``simdram.rows_in``, ``simdram.commands`` and
-``simdram.rows_out`` in that order.
+holds ``simdram.layout``, ``simdram.charge`` and ``simdram.execute``.  On
+``pallas``, ``simdram.execute`` holds ``simdram.rows_in``,
+``simdram.commands`` and ``simdram.rows_out`` in that order; on
+``unrolled`` it launches one compiled program and holds no span, and its
+``compiled`` stat says whether the call compiled that program.
 """
 import dataclasses
 import glob
@@ -15,6 +17,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
+from repro.core.unrolled import EXECUTABLES
 from repro.ops import SimdramMachine
 from repro.simdram.layout import BitplaneArray
 
@@ -86,6 +89,23 @@ def _check_nesting(spans, backend):
     assert all(c.stats == {"mode": "analytic"} for c in charges)
     assert execute.stats["backend"] == backend
     assert execute.stats["banks"] == BANKS
+    return execute
+
+
+def case_nesting_unrolled(tmp_path):
+    """A warm call on ``unrolled`` is one compiled program: no span under
+    ``simdram.execute``, and nothing compiled."""
+    spans, _ = _warm_call(tmp_path, "unrolled")
+    execute = _check_nesting(spans, "unrolled")
+    assert [s for s in spans if s.name in EXEC_CHILDREN] == []
+    assert execute.stats["compiled"] == 0
+
+
+def case_nesting_pallas(tmp_path):
+    """The Pallas backend (interpret mode on the CPU) has three children
+    under ``simdram.execute``."""
+    spans, _ = _warm_call(tmp_path, "pallas")
+    execute = _check_nesting(spans, "pallas")
     children = [s for s in spans if s.name in EXEC_CHILDREN]
     assert [s.name for s in children] == EXEC_CHILDREN
     for s in children:
@@ -94,16 +114,20 @@ def _check_nesting(spans, backend):
         assert first.end <= then.start
 
 
-def case_nesting_unrolled(tmp_path):
-    spans, _ = _warm_call(tmp_path, "unrolled")
-    _check_nesting(spans, "unrolled")
-
-
-def case_nesting_pallas(tmp_path):
-    """The Pallas backend (interpret mode on the CPU) has the same three
-    children under ``simdram.execute``."""
-    spans, _ = _warm_call(tmp_path, "pallas")
-    _check_nesting(spans, "pallas")
+def case_execute_compiles_on_first_call(tmp_path):
+    """The first call of a shape compiles the ``unrolled`` program inside
+    ``simdram.execute`` and says so; the next call does not."""
+    EXECUTABLES.clear()
+    m = SimdramMachine(banks=BANKS, backend="unrolled")
+    a, b = _operands()
+    add = m.op("addition")
+    first = _record(tmp_path / "first", lambda: add(a, b, n_bits=N_BITS))
+    again = _record(tmp_path / "again", lambda: add(a, b, n_bits=N_BITS))
+    (execute,) = _named(first, "simdram.execute")
+    assert execute.stats["compiled"] == 1
+    (execute,) = _named(again, "simdram.execute")
+    assert execute.stats["compiled"] == 0
+    assert EXECUTABLES.stats()["misses"] == 1
 
 
 def case_values_layout(tmp_path):
@@ -147,7 +171,7 @@ def case_compile_on_first_call_only(tmp_path):
 
 CASES = [case_nesting_unrolled, case_nesting_pallas, case_values_layout,
          case_planes_bypass_layout, case_n_cmds_is_trace_length,
-         case_compile_on_first_call_only]
+         case_compile_on_first_call_only, case_execute_compiles_on_first_call]
 
 
 @pytest.mark.parametrize("case", CASES, ids=[c.__name__[5:] for c in CASES])
