@@ -1,19 +1,29 @@
 """Run one benchmark cell once: set-up, the measured window, the check.
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
-its configuration file, ``traffic/<traffic>.json`` and one reader per
-metric in ``metrics/<metric>.py`` (``read(run) -> float | None``).  A
-metric ``<base>.<part>`` with no file of its own is read by
+its configuration file, ``traffic/<traffic>.json``, one builder per source
+kind in ``sources/<kind>.py`` (``build(name, spec, banks, lanes, seed) ->
+{name: Bound}``), one answer per answer kind in ``answers/<kind>.py``
+(``read``, ``keep``, ``compare``, ``out_bytes``; see ``generator.py``) and
+one reader per metric in ``metrics/<metric>.py`` (``read(run) -> float |
+None``).  A metric ``<base>.<part>`` with no file of its own is read by
 ``metrics/<base>.py``, so one quantity split by the end-to-end metric it
-moves has one reader.  Adding a cell, configuration, traffic mix or metric
-adds files and entries only.
+moves has one reader.  Adding a cell, configuration, traffic mix, source
+kind, answer kind or metric adds files and entries only.
+
+The window keeps the answers of a sample of its units, drawn from the seed
+as it runs (:class:`Sample`: every place of the traffic's cycle and every
+entry of every source it reads at least once, up to :data:`CHECKED` units
+in all); once it has closed, they are compared with the plain reference.
 """
 from __future__ import annotations
 
+import collections
 import glob
-import importlib.util
+import heapq
 import json
 import pathlib
+import random
 import shutil
 import sys
 import tempfile
@@ -31,9 +41,13 @@ HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 if str(ROOT / "src") not in sys.path:       # the system under test
     sys.path.insert(0, str(ROOT / "src"))
-# every number compared is exact: a lane or a count that differs from the
-# reference is wrong
-LIMITS = {"wrong_lanes": 0, "wrong_counts": 0}
+# every number compared is exact: a lane, a count or a sum that differs from
+# the reference is wrong, so every limit is 0 and no answer kind sets one
+LIMIT = 0
+# units compared a run, at the most: the reference takes up to about 70 ms
+# a unit at the cells' size on the host, so the check stays shorter than
+# the window
+CHECKED = 96
 
 
 def load_cell(name: str) -> types.SimpleNamespace:
@@ -57,6 +71,7 @@ def load_cell(name: str) -> types.SimpleNamespace:
         return name in metric.get("workloads", [name])
     return types.SimpleNamespace(
         name=name, chips=cell["chips"], config=config, traffic=traffic,
+        answer=generator.answer_kind(traffic),
         end_to_end=[m for m in bench["end_to_end"] if applies(m)],
         per_layer=[m for m in bench["per_layer"] if applies(m)])
 
@@ -87,11 +102,8 @@ def reader(metric: str):
     path = HERE / "metrics" / f"{metric}.py"
     if not path.exists():
         path = HERE / "metrics" / f"{metric.split('.')[0]}.py"
-    spec = importlib.util.spec_from_file_location(
-        "metric_" + metric.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return generator.load_module(
+        path, "metric_" + metric.replace(".", "_")).read
 
 
 class _CompileCounter:
@@ -114,30 +126,104 @@ class _CompileCounter:
         jax.monitoring.unregister_event_duration_listener(self)
 
 
-def _window(machine, cell, sources, seconds: float, width_delta: int):
+class Sample:
+    """The units whose answers the window keeps for the check, drawn from
+    the seed as it runs.  A unit falls in the strata of its place in the
+    traffic's cycle and of each source entry it reads (the names that one
+    spec binds, such as a pair's two bounds, share their strata).  Each
+    unit gets two priorities, drawn from the seed in the order units run.
+    Every stratum keeps its unit of the lowest first priority, so each
+    place of the cycle (an operation) and each source entry (an operand
+    pair, a column chunk, a query's bounds) is compared once, uniformly
+    among its units; of all units, the ``size - n`` of the lowest second
+    priority join them, ``n`` the strata, so a window of at most ``size -
+    n`` units keeps every unit.  Only the sample's answers are held: at
+    most ``size`` of them."""
+
+    def __init__(self, traffic: dict, sources: dict, seed: int,
+                 size: int) -> None:
+        cycle = len(traffic["units"])
+        self._reads = [[sources[a] for a in generator.unit_inputs(
+            generator.unit_steps(traffic, k), sources, k)]
+            for k in range(cycle)]          # the sources each place reads
+        entries: dict = {}
+        for src in sum(self._reads, []):
+            entries[src.key] = max(entries.get(src.key, 0), len(src.entries))
+        self.n = cycle + sum(entries.values())
+        self._rng = random.Random(f"{seed}/checked")
+        self._rest = max(0, size - self.n)
+        self._best: dict = {}               # stratum -> (priority, unit)
+        self._worst = 1.0                   # highest in _best once all filled
+        self._low: list = []                # heap of (-priority, unit)
+        self._refs: collections.Counter = collections.Counter()
+        self.kept: dict = {}                # unit -> what its answer kept
+
+    def strata(self, unit: int) -> set:
+        place = unit % len(self._reads)
+        return {("unit", place)} | {(src.key, src.index(unit))
+                                    for src in self._reads[place]}
+
+    def offer(self, unit: int) -> bool:
+        """Whether the answer of ``unit``, the next unit run, joins the
+        sample; drops the answers that it displaces."""
+        p, q = self._rng.random(), self._rng.random()
+        joins = False
+        if p < self._worst:                 # else it wins no stratum
+            for s in self.strata(unit):
+                held = self._best.get(s)
+                if held is None or p < held[0]:
+                    if held is not None:
+                        self._release(held[1])
+                    self._best[s] = (p, unit)
+                    self._refs[unit] += 1
+                    joins = True
+            if joins and len(self._best) == self.n:
+                self._worst = max(v[0] for v in self._best.values())
+        if len(self._low) < self._rest:
+            heapq.heappush(self._low, (-q, unit))
+        elif self._rest and q < -self._low[0][0]:
+            self._release(heapq.heapreplace(self._low, (-q, unit))[1])
+        else:
+            return joins
+        self._refs[unit] += 1
+        return True
+
+    def _release(self, unit: int) -> None:
+        self._refs[unit] -= 1
+        if not self._refs[unit]:
+            del self._refs[unit]
+            self.kept.pop(unit, None)
+
+
+def _window(machine, cell, sources, seconds: float, width_delta: int,
+            sample: Sample):
     """The closed loop: one caller runs unit after unit, each to its ready
     result, until ``seconds`` have passed and a whole cycle of units is
-    done.  Returns (outputs, counts, start, each unit's end), host clock."""
-    traffic = cell.traffic
+    done.  What the answer keeps of the units that join ``sample`` goes
+    into ``sample.kept``.  Returns (start, each unit's end), host clock."""
+    traffic, answer = cell.traffic, cell.answer
     cycle = len(traffic["units"])
-    outputs, counts, ends = [], [], []
+    ends = []
     annotate = jax.profiler.TraceAnnotation
     t0 = time.perf_counter()
     with annotate("window"):
         unit = 0
         while True:
+            steps = generator.unit_steps(traffic, unit)
             with annotate("call"):
-                out = generator.run_unit(machine, traffic, sources, unit,
-                                         width_delta)
-            if traffic["answer"] == "count":
-                with annotate("count"):
-                    counts.append(generator.count_lanes(out))
-            outputs.append(out)
+                outs = generator.run_unit(machine, traffic, sources, unit,
+                                          width_delta)
+            got = None
+            if answer.read is not None:
+                with annotate(answer.kind):
+                    got = answer.read(steps, outs)
+            if sample.offer(unit):
+                sample.kept[unit] = answer.keep(steps, outs, got)
             ends.append(time.perf_counter())
             unit += 1
             if unit % cycle == 0 and ends[-1] - t0 >= seconds:
                 break
-    return outputs, counts, t0, ends
+    return t0, ends
 
 
 def _quartiles(durations, cycle: int) -> list:
@@ -156,26 +242,19 @@ def _since(t0: float, marks: dict) -> dict:
     return out
 
 
-def check(cell, sources, outputs, counts) -> dict:
-    """Compare every unit of the window with the plain reference."""
-    counted = cell.traffic["answer"] == "count"
-    wrong = {"wrong_lanes": 0, **({"wrong_counts": 0} if counted else {})}
-    failed = 0
-    for unit, out in enumerate(outputs):
+def check(cell, sources, kept: dict) -> dict:
+    """Compare the kept answers, unit -> what the answer kept, with the
+    plain reference."""
+    wrong, failed = {}, 0
+    for unit in sorted(kept):
         steps = generator.unit_steps(cell.traffic, unit)
         inputs = generator.unit_inputs(steps, sources, unit)
         env = reference.eval_steps(
             steps, {a: sources[a].host_values(i) for a, i in inputs.items()})
-        want = env[steps[-1].get("out", "out")]
-        if hasattr(out, "planes"):
-            bad = reference.lanes_differing(np.asarray(out.planes), want)
-        else:
-            bad = int((np.asarray(out).astype(np.int64) != want).sum())
-        wrong["wrong_lanes"] += bad
-        bad_count = counted and counts[unit] != int(want.sum())
-        if counted:
-            wrong["wrong_counts"] += int(bad_count)
-        failed += bool(bad or bad_count)
+        numbers = cell.answer.compare(steps, kept[unit], env)
+        for k, v in numbers.items():
+            wrong[k] = wrong.get(k, 0) + v
+        failed += any(numbers.values())
     return {"compared": wrong, "failed": failed}
 
 
@@ -199,10 +278,11 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         banks, lanes, seed)
     marks.append(time.perf_counter())
     for unit in range(len(traffic["units"])):       # every shape, once
-        out = generator.run_unit(machine, traffic, sources, unit,
-                                 width_delta)
-        if traffic["answer"] == "count":
-            generator.count_lanes(out)
+        outs = generator.run_unit(machine, traffic, sources, unit,
+                                  width_delta)
+        if cell.answer.read is not None:
+            cell.answer.read(generator.unit_steps(traffic, unit), outs)
+    sample = Sample(traffic, sources, seed, CHECKED)
     marks.append(time.perf_counter())
     setup_s = marks[-1] - t_start
 
@@ -213,8 +293,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         jax.profiler.start_trace(profile_dir, profiler_options=options)
     try:
         with _CompileCounter() as compiles, machine.timed() as stats:
-            outputs, counts, t0, ends = _window(machine, cell, sources,
-                                                seconds, width_delta)
+            t0, ends = _window(machine, cell, sources, seconds, width_delta,
+                               sample)
         window_s = ends[-1] - t0
     finally:
         if trace:
@@ -230,13 +310,16 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
                for d in devices)
     totals = stats.snapshot()
     del machine, stats
-    verdict = check(cell, sources, outputs, counts)
+    t_check = time.perf_counter()
+    verdict = check(cell, sources, sample.kept)
+    check_s = time.perf_counter() - t_check
 
+    units = range(len(ends))
     record = types.SimpleNamespace(
-        setup_s=setup_s, window_s=window_s, units=len(outputs),
-        elements=len(outputs) * banks * lanes,
-        least_bytes=[generator.least_bytes(traffic, sources, banks, lanes, u)
-                     for u in range(len(outputs))],
+        setup_s=setup_s, window_s=window_s, units=len(ends),
+        elements=sum(generator.unit_rows(traffic, sources, u) for u in units),
+        least_bytes=[generator.least_bytes(cell.answer, traffic, sources,
+                                           banks, lanes, u) for u in units],
         trace=reduction, peaks=cell.peaks)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
@@ -245,7 +328,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": peak}
-    result = {"attempted": len(outputs), "failed": verdict["failed"],
+    result = {"attempted": len(ends), "failed": verdict["failed"],
               "metrics": metrics, "device": device}
     if reduction is not None:
         device["busy_s"] = reduction.busy_s
@@ -253,12 +336,13 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         idle = sorted(reduction.idle_by_span().items(), key=lambda kv: -kv[1])
         result["breakdown"] = {"device_ops": reduction.top_ops(10),
                                "idle_gaps": [[n, v] for n, v in idle[:10]]}
-    compared = {k: {"value": v, "limit": LIMITS[k]}
+    compared = {k: {"value": v, "limit": LIMIT}
                 for k, v in verdict["compared"].items()}
-    result["correct"] = bool(outputs) and all(
+    result["correct"] = bool(sample.kept) and all(
         c["value"] <= c["limit"] for c in compared.values())
     result["compared"] = compared
-    info = {"units": len(outputs), "window_s": window_s,
+    info = {"units": len(ends), "checked": len(sample.kept),
+            "check_s": check_s, "window_s": window_s,
             "setup_parts_s": {**_since(t_start, {**(early or {}),
                                                  "program": marks[0]}),
                               "sources": marks[1] - marks[0],

@@ -6,11 +6,12 @@
 The cell, its configuration, traffic and metrics are found by name from
 ``BENCHMARK.json`` (see ``harness.py``).  The run sets up (imports, data
 from the seed, one pass over every shape the traffic uses), measures a
-closed loop for ``--seconds`` and compares every answer of the window with
-the plain reference.  It prints the numbers compared, each with its limit,
-as the last lines of standard error, and one JSON object as the last line
-of standard output.  ``--trace 1`` runs the window under the profiler and
-reports the per-layer metrics instead of the end-to-end ones.
+closed loop for ``--seconds`` and compares the answers of a sample of the
+window's units, drawn from the seed, with the plain reference.  It prints
+the numbers compared, each with its limit, as the last lines of standard
+error, and one JSON object as the last line of standard output.
+``--trace 1`` runs the window under the profiler and reports the per-layer
+metrics instead of the end-to-end ones.
 
 It exits 1 and prints no result when JAX finds no TPU, fewer chips than
 the cell asks for, or a chip that ``peaks.json`` does not list.
