@@ -266,9 +266,21 @@ def compile_bitcount(n_bits: int, optimize: bool = True) -> UProgram:
     return prog
 
 
-def compile_multiplication(n_bits: int, optimize: bool = True) -> UProgram:
-    """Truncating n×n→n multiply: n gated-add passes; shifts are free row
-    re-indexing (vertical layout).  Paper: 11n²−5n−1 (class 3)."""
+def compile_multiplication(n_bits: int, optimize: bool = True,
+                           a_bits: int | None = None,
+                           b_bits: int | None = None) -> UProgram:
+    """Truncating multiply: unsigned, zero-extended operands, the product
+    modulo 2**n; one gated-add pass per bit of ``b`` (gated by that bit);
+    shifts are free row re-indexing (vertical layout).  Paper: 11n²−5n−1
+    (class 3) at full width.
+
+    ``a_bits`` / ``b_bits`` are the operands' widths (default ``n_bits``).
+    Pass j adds ``a << j`` into the accumulator bits ``j … min(j + a_bits,
+    n − 1)``: after it the sum is below ``2**(a_bits + j + 1)``, so the
+    carry never reaches a higher bit, and only ``min(b_bits, n)`` passes
+    can be nonzero.  At full width this is the n×n→n program."""
+    a_bits = n_bits if a_bits is None else min(int(a_bits), n_bits)
+    b_bits = n_bits if b_bits is None else min(int(b_bits), n_bits)
     progs: list[UProgram] = []
     # zero the accumulator rows
     zero = UProgram("mul_zero", n_bits,
@@ -276,10 +288,11 @@ def compile_multiplication(n_bits: int, optimize: bool = True) -> UProgram:
                               for i in range(n_bits)], body=[], body_reps=0)
     progs.append(zero)
     base = compile_slice(spec_gated_add(), n_bits, optimize=optimize)
-    for j in range(n_bits):
+    for j in range(b_bits):
         pj = rebase(base, {"acc": j, "gate": j},
                     renames={"acc": "out", "gate": "b"})
-        pj = dataclasses.replace(pj, body_reps=n_bits - j, name=f"mul_pass{j}")
+        pj = dataclasses.replace(pj, body_reps=min(a_bits + 1, n_bits - j),
+                                 name=f"mul_pass{j}")
         progs.append(pj)
     return concat_programs("multiplication", progs, n_bits,
                            inputs=("a", "b"), outputs=("out",))
@@ -390,6 +403,9 @@ class OperationDef:
     compile_fn: object            # (n_bits: int, optimize: bool) -> UProgram
     op_class: int | None = None   # paper Table-5 class (1/2/3), if meaningful
     builtin: bool = False
+    # (n_bits, in_bits, optimize) -> UProgram for operands narrower than
+    # n_bits (``in_bits``: each operand's width); None: zero-extend them
+    compile_widths: object = None
 
 
 _OPERATIONS: dict[str, OperationDef] = {}
@@ -397,6 +413,7 @@ _OPERATIONS: dict[str, OperationDef] = {}
 
 def register_operation(name: str, compile_fn, *, op_class: int | None = None,
                        paper_count=None, override: bool = False,
+                       compile_widths=None,
                        _builtin: bool = False) -> OperationDef:
     """Register a new operation with the process-wide op table.
 
@@ -406,7 +423,9 @@ def register_operation(name: str, compile_fn, *, op_class: int | None = None,
     the replay-timing substrate all pick it up with no other change.
     ``paper_count`` optionally records a closed-form command count (joins
     ``PAPER_COUNTS``); ``override=True`` replaces an existing non-builtin
-    registration.
+    registration.  ``compile_widths(n_bits, in_bits, optimize)``, if given,
+    compiles the op for operands narrower than ``n_bits``
+    (:func:`compile_operation`).
     """
     if not callable(compile_fn):
         raise TypeError(f"compile_fn for {name!r} must be callable")
@@ -417,7 +436,7 @@ def register_operation(name: str, compile_fn, *, op_class: int | None = None,
         if not override:
             raise ValueError(f"operation {name!r} already registered "
                              "(pass override=True to replace it)")
-    d = OperationDef(name, compile_fn, op_class, _builtin)
+    d = OperationDef(name, compile_fn, op_class, _builtin, compile_widths)
     _OPERATIONS[name] = d
     if op_class is not None:
         CLASS_OF[name] = op_class
@@ -463,15 +482,33 @@ def list_operations() -> tuple[str, ...]:
     return tuple(sorted(_OPERATIONS))
 
 
-def compile_operation(name: str, n_bits: int, optimize: bool = True) -> UProgram:
+def compile_operation(name: str, n_bits: int, optimize: bool = True,
+                      in_bits: tuple | None = None) -> UProgram:
     """Compile any registered SIMDRAM operation for n-bit elements.
 
     ``optimize=False`` skips Step-1 MIG optimization, yielding the naive
     AND/OR/NOT-equivalent command stream — this is the paper's Ambit
     baseline (§6: 'evaluate all 16 SIMDRAM operations in Ambit using their
     equivalent AND/OR/NOT-based implementations').
+
+    ``in_bits`` (each operand's width, in input order) compiles the
+    operation's μProgram for operands that narrow, where it has one
+    (:func:`width_aware`); the result is the same as at full width on the
+    operands zero-extended.
     """
-    return get_operation(name).compile_fn(n_bits, optimize)
+    op = get_operation(name)
+    if in_bits is None:
+        return op.compile_fn(n_bits, optimize)
+    if op.compile_widths is None:
+        raise ValueError(f"{name!r} has no μProgram for narrower operands")
+    return op.compile_widths(n_bits, tuple(in_bits), optimize)
+
+
+def width_aware(name: str) -> bool:
+    """Whether the registered operation ``name`` compiles a shorter
+    μProgram for operands narrower than its ``n_bits``."""
+    op = _OPERATIONS.get(name)
+    return op is not None and op.compile_widths is not None
 
 
 def _register_builtins() -> None:
@@ -504,9 +541,14 @@ def _register_builtins() -> None:
             n, optimize=opt),
         "division": lambda n, opt=True: compile_division(n, optimize=opt),
     }
+    widths = {
+        "multiplication": lambda n, in_bits, opt=True: compile_multiplication(
+            n, optimize=opt, a_bits=in_bits[0], b_bits=in_bits[1]),
+    }
     assert set(builtins) == set(ALL_OPS)
     for name, fn in builtins.items():
-        register_operation(name, fn, op_class=CLASS_OF[name], _builtin=True)
+        register_operation(name, fn, op_class=CLASS_OF[name],
+                           compile_widths=widths.get(name), _builtin=True)
 
 
 _register_builtins()

@@ -403,8 +403,15 @@ def canonical_uops(prog: UProgram) -> list:
 # ---------------------------------------------------------------------------
 
 
+def format_widths(in_bits: tuple) -> str:
+    """Operand widths as a span stat: ``(24, 7)`` reads ``"24x7"`` (a
+    profiler stat's value may hold no comma)."""
+    return "x".join(str(b) for b in in_bits)
+
+
 class TraceCache:
-    """A μProgram Memory: compile + lower once per ``(op, n_bits, optimize)``.
+    """A μProgram Memory: compile + lower once per ``(op, n_bits, optimize)``
+    (and per operand widths, for a μProgram compiled for narrower operands).
 
     The paper's control unit keeps the finished μPrograms in a small
     scratchpad (Fig. 7); this class mirrors it as a bounded LRU cache over
@@ -414,7 +421,8 @@ class TraceCache:
     owns a private instance, so concurrent sessions never share compiles
     or counters.
 
-    ``compile_fn(name, n_bits, optimize) → UProgram`` resolves a miss —
+    ``compile_fn(name, n_bits, optimize) → UProgram`` resolves a miss (a
+    fetch for narrower operands adds ``in_bits=``) —
     ``None`` means the process-wide op registry
     (:func:`repro.core.circuits.compile_operation`).  ``capacity=None``
     is unbounded.  ``verify=True`` (default) statically verifies every
@@ -479,21 +487,32 @@ class TraceCache:
         self._schedule_misses = 0
         _ALL_CACHES.add(self)
 
-    def _compile(self, name: str, n_bits: int, optimize: bool) -> UProgram:
+    def _compile(self, name: str, n_bits: int, optimize: bool,
+                 in_bits: tuple | None = None) -> UProgram:
+        widths = {} if in_bits is None else {"in_bits": in_bits}
         if self._compile_fn is not None:
-            return self._compile_fn(name, n_bits, optimize)
+            return self._compile_fn(name, n_bits, optimize, **widths)
         from .circuits import compile_operation
-        return compile_operation(name, n_bits, optimize=optimize)
+        return compile_operation(name, n_bits, optimize=optimize, **widths)
 
     def get(self, name: str, n_bits: int, optimize: bool = True,
-            verify: bool | None = None) -> tuple[UProgram, LoweredTrace]:
+            verify: bool | None = None, in_bits: tuple | None = None
+            ) -> tuple[UProgram, LoweredTrace]:
         """Fetch-or-compile the ``(UProgram, LoweredTrace)`` pair.
 
         ``verify=None`` uses the cache's default (see the class docstring);
         a trace that fails verification raises
         :class:`~repro.core.tracelint.TraceLintError` and never enters the
-        cache."""
+        cache.  ``in_bits`` (the operands' widths) fetches the μProgram
+        compiled for operands that narrow (see
+        :func:`repro.core.circuits.width_aware`), keyed by the widths too;
+        ``None`` is the full-width program under its own key."""
         key = (name, int(n_bits), bool(optimize))
+        span = {"op": name, "n_bits": n_bits}
+        if in_bits is not None:
+            in_bits = tuple(int(b) for b in in_bits)
+            key += (in_bits,)
+            span["in_bits"] = format_widths(in_bits)
         # the whole miss path holds the lock: compiling outside it would
         # let two threads synthesize the same key concurrently and tear
         # the hit/miss counters
@@ -508,8 +527,8 @@ class TraceCache:
                     hit[1].lint().raise_for_errors()
                 return hit
             self._misses += 1
-            with TraceAnnotation("simdram.compile", op=name, n_bits=n_bits):
-                prog = self._compile(name, n_bits, bool(optimize))
+            with TraceAnnotation("simdram.compile", **span):
+                prog = self._compile(name, n_bits, bool(optimize), in_bits)
                 trace = lower_program(prog)
                 if self.verify if verify is None else verify:
                     trace.lint().raise_for_errors()

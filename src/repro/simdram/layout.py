@@ -75,6 +75,7 @@ _MOVE_HOOKS: list = []
 def register_movement_hook(hook) -> None:
     """Register ``hook(kind: str, n_rows: int, banks: int | None, planes)``
     to observe in-DRAM row relocations (``kind`` is "intra" or "inter";
+    "read" is a read-out's rows, charged like "intra";
     ``banks`` is the destination bank count of an inter-bank scatter and
     ``planes`` the scattered plane array — both None for gathers and
     intra-bank hops).  Scoped per-session observation goes through
@@ -359,9 +360,10 @@ class BitplaneArray:
 def np_to_bitplanes(values: np.ndarray, n_bits: int) -> np.ndarray:
     e = values.shape[0]
     assert e % LANE_WORD == 0
-    u = values.astype(np.uint32)
-    bits = (u[None, :] >> np.arange(n_bits, dtype=np.uint32)[:, None]) & 1
-    bits = bits.reshape(n_bits, e // LANE_WORD, LANE_WORD)
+    wide = np.uint64 if n_bits > 32 else np.uint32     # values past 32 bits
+    u = values.astype(wide)
+    bits = (u[None, :] >> np.arange(n_bits, dtype=wide)[:, None]) & wide(1)
+    bits = bits.astype(np.uint32).reshape(n_bits, e // LANE_WORD, LANE_WORD)
     return (bits << np.arange(LANE_WORD, dtype=np.uint32)).sum(-1).astype(np.uint32)
 
 

@@ -73,7 +73,7 @@ from ..core.backends import (PerfStats, execute_heterogeneous,
 from ..core.backends import timed as _timed_execution
 from ..core.compiler import SliceSpec, compile_slice
 from ..core.graph import LogicGraph
-from ..core.trace import GLOBAL_TRACE_CACHE, TraceCache
+from ..core.trace import GLOBAL_TRACE_CACHE, TraceCache, format_widths
 from .layout import (LANE_WORD, BitplaneArray, register_movement_hook,
                      register_transpose_hook)
 from .scheduler import BankScheduler, RequestTiming, ScheduleResult
@@ -149,22 +149,36 @@ class BoundOp:
     def __repr__(self) -> str:
         return f"<BoundOp {self.name!r} on {self.machine!r}>"
 
-    def program(self, n_bits: int = 8, optimize: bool = True):
-        """The cached ``(UProgram, LoweredTrace)`` pair for this width."""
+    def program(self, n_bits: int = 8, optimize: bool = True,
+                in_bits: tuple | None = None):
+        """The cached ``(UProgram, LoweredTrace)`` pair for this width;
+        ``in_bits`` (the operands' widths, where some are narrower than
+        ``n_bits``) fetches the μProgram compiled for them, on an op that
+        has one (:func:`~repro.core.circuits.width_aware`)."""
+        if in_bits is not None and self.machine._width_aware(self.name):
+            return self.machine.memory.get(self.name, n_bits, optimize,
+                                           in_bits=in_bits)
         return self.machine.memory.get(self.name, n_bits, optimize)
 
     def __call__(self, *operands, n_bits: int = 8, out_bits: int | None = None,
                  signed_out: bool = False, optimize: bool = True,
                  backend: str | None = None):
         from ..ops.bbops import _run_op
+        # the widths the op reads of each operand: a horizontal value is
+        # converted at n_bits, a plane-resident one holds its own
+        in_bits = tuple(min(x.n_bits, n_bits) if isinstance(x, BitplaneArray)
+                        else n_bits for x in operands)
+        if all(b == n_bits for b in in_bits):
+            in_bits = None
+        span = {} if in_bits is None else {"in_bits": format_widths(in_bits)}
         with TraceAnnotation("simdram.op", op=self.name, n_bits=n_bits,
-                             banks=self.machine.banks), \
+                             banks=self.machine.banks, **span), \
                 self.machine.session():
             # one μProgram-Memory access per call (the fetched pair rides
             # through to execution), and operand layout conversion happens
             # inside the session so the machine's scoped hooks observe the
             # input transposition passes too
-            compiled = self.program(n_bits, optimize)
+            compiled = self.program(n_bits, optimize, in_bits)
             prog = compiled[0]
             # inputs may repeat a name (e.g. relu reads 'a' twice) — one
             # operand binds each distinct input array
@@ -332,12 +346,20 @@ class SimdramMachine:
                 f"ops={len(self._ops)} user-defined)")
 
     # -- Step 1+2: operation definition -------------------------------------
-    def _compile(self, name: str, n_bits: int, optimize: bool):
+    def _compile(self, name: str, n_bits: int, optimize: bool,
+                 in_bits: tuple | None = None):
         fn = self._ops.get(name)
         if fn is not None:
             return fn(n_bits, optimize)
         from ..core.circuits import compile_operation
-        return compile_operation(name, n_bits, optimize=optimize)
+        return compile_operation(name, n_bits, optimize=optimize,
+                                 in_bits=in_bits)
+
+    def _width_aware(self, name: str) -> bool:
+        """Whether ``name`` compiles for narrower operands here: a
+        registry op with such a μProgram, not redefined on this machine."""
+        from ..core.circuits import width_aware
+        return name not in self._ops and width_aware(name)
 
     def define_op(self, name: str, build_graph=None, spec=None, *,
                   invariants: dict | None = None, states: dict | None = None,
@@ -579,6 +601,15 @@ class SimdramMachine:
             banks = self.banks
         return simdram_pipeline(banks=banks, backend=backend, machine=self,
                                 **kw)
+
+    def group_sums(self, values, masks):
+        """Exact sums of plane-resident ``values`` over the lanes of each
+        1-bit mask, with each mask's lane count, read in one device program
+        and charged to this machine (see
+        :func:`repro.simdram.readout.group_sums`)."""
+        from .readout import group_sums
+        with self.session():
+            return group_sums(values, masks)
 
     # -- scheduled execution: submit / drain ---------------------------------
     def tenant_stats(self, tenant: str = "default") -> PerfStats:

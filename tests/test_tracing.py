@@ -169,9 +169,35 @@ def case_compile_on_first_call_only(tmp_path):
     assert len(_named(again, "simdram.op")) == 1
 
 
+def case_in_bits_on_narrow_operands(tmp_path):
+    """Plane-resident operands narrower than ``n_bits`` put their widths on
+    ``simdram.op``; a multiplication compiles for them, and says so on
+    ``simdram.compile``.  Full-width operands add no stat (the cases
+    above)."""
+    m = SimdramMachine(banks=BANKS)
+    a, b = _operands()
+    pa = BitplaneArray.from_values(a & 0x1F, 5)
+    pb = BitplaneArray.from_values(b & 0x7, 3)
+    spans = _record(tmp_path, lambda: m.op("multiplication")(
+        pa, pb, n_bits=N_BITS).planes)
+    (op,) = _named(spans, "simdram.op")
+    assert op.stats == {"op": "multiplication", "n_bits": N_BITS,
+                        "banks": BANKS, "in_bits": "5x3"}
+    (compile_,) = _named(spans, "simdram.compile")
+    assert compile_.stats == {"op": "multiplication", "n_bits": N_BITS,
+                              "in_bits": "5x3"}
+    spans = _record(tmp_path / "add", lambda: m.op("addition")(
+        pa, pb, n_bits=N_BITS).planes)
+    (op,) = _named(spans, "simdram.op")
+    assert op.stats["in_bits"] == "5x3"
+    (compile_,) = _named(spans, "simdram.compile")
+    assert compile_.stats == {"op": "addition", "n_bits": N_BITS}
+
+
 CASES = [case_nesting_unrolled, case_nesting_pallas, case_values_layout,
          case_planes_bypass_layout, case_n_cmds_is_trace_length,
-         case_compile_on_first_call_only, case_execute_compiles_on_first_call]
+         case_compile_on_first_call_only, case_execute_compiles_on_first_call,
+         case_in_bits_on_narrow_operands]
 
 
 @pytest.mark.parametrize("case", CASES, ids=[c.__name__[5:] for c in CASES])
